@@ -46,9 +46,8 @@ from repro.core.flatspace import FlatSpace
 from repro.data import SyntheticLM, make_train_batch
 from repro.kernels.quantize import TILE_BLOCKS
 from repro.kernels.tiling import padded_size
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
-from repro.launch.train import make_cpu_mesh
 from repro.models.counting import count_params
 
 
@@ -77,7 +76,7 @@ def run(steps: int = 20, seq: int = 64, batch: int = 8) -> List[Dict]:
     cfg = reduced(get_arch("biglstm"), vocab=512)
     shape = ShapeConfig(name="bench", seq_len=seq, global_batch=batch,
                         kind="train")
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     with mesh:
         plan = resolve_plan(cfg, mesh, optimizer="local_adaalter")
 
@@ -193,13 +192,13 @@ import dataclasses, json
 import jax
 from repro.configs import OptimizerConfig, ShapeConfig, get_arch, reduced
 from repro.configs.base import SyncConfig
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs, train_batch_specs
 from benchmarks.bench_flat_step import count_pallas_calls, _mk_opt
 
 cfg = reduced(get_arch("biglstm"), vocab=512)
 shape = ShapeConfig(name="bench", seq_len=64, global_batch=8, kind="train")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = worker_mesh(2)
 out = {}
 with mesh:
     plan = resolve_plan(cfg, mesh, optimizer="local_adaalter")

@@ -41,6 +41,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller step counts (CI mode)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     which = [w for w in (args.only.split(",") if args.only else ALL) if w]
 
     rows = []

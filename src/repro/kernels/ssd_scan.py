@@ -31,10 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX 0.4.x ships the TPU compiler knobs as ``TPUCompilerParams``; newer
-# releases renamed it to ``CompilerParams``. Accept either.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-
 
 def _kernel(x_ref, b_ref, c_ref, da_ref, y_ref, s_ref):
     """One (batch, head, chunk) tile. Shapes:
@@ -97,7 +93,7 @@ def ssd_scan(xbar, Bm, Cm, dA, *, interpret: bool = True):
         out_specs=pl.BlockSpec((1, 1, 1, c, hd), lambda i, j, k: (i, j, k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nh, nz, c, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_t, Bm, Cm, da_t)

@@ -1,16 +1,21 @@
-"""Production meshes and per-arch parallelism-plan resolution.
+"""Device meshes and per-arch parallelism-plan resolution.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state — required because the
-dry-run must set XLA_FLAGS before any jax initialization.
+Every mesh in the repo is built by :func:`auto_mesh`, over whatever devices
+JAX reports: the TPU chips on a TPU host, the (virtual) host devices under
+``JAX_PLATFORMS=cpu``. Its axes are ``Auto``, so GSPMD propagates shardings
+through the vmapped worker axis and honours ``with_sharding_constraint``.
+The builders are FUNCTIONS (not module-level constants) so that importing
+this module never touches jax device state — required because the dry-run
+must set XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
 import os
 import re
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import ModelConfig, ParallelismPlan
 
@@ -48,27 +53,36 @@ def require_host_devices(n: int, *, strict: bool = True) -> bool:
     return False
 
 
+def auto_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """Mesh of ``shape`` over ``devices`` (default: all of ``jax.devices()``)
+    with every axis ``Auto``. ``jax.make_mesh`` alone defaults to
+    ``Explicit`` axes, under which the vmapped worker axis and the flat
+    plane's sharding constraints do not trace."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def worker_mesh(n_workers: Optional[int] = None, *, devices=None):
+    """(data, model) training mesh over ``devices`` (default: all devices).
+
+    ``n_workers`` sizes the data (local-SGD worker) axis; the remaining
+    devices go to the model axis, which a sharded ``--flat`` run uses for
+    its plane shards. ``None`` puts every device on the data axis, and so
+    does a request that does not divide the device count.
+    """
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
+    data = n if n_workers is None else max(1, min(n_workers, n))
+    if n % data:
+        data = n
+    return auto_mesh((data, n // data), ("data", "model"), devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
-    """Small mesh for CPU multi-device tests (host platform device count)."""
-    return jax.make_mesh(shape, axes)
-
-
-def make_worker_shard_mesh(n_workers: int, n_shards: int = 1,
-                           axes=("data", "model")):
-    """2-D (workers × shards) CPU mesh for sharded ``--flat`` runs/tests.
-
-    ``data`` carries the local-SGD workers, ``model`` the per-worker
-    FSDP/TP plane shards (``sharding.partition.plane_shard_axes``). Sets
-    the ``XLA_FLAGS`` host-device override when it can still take effect.
-    """
-    require_host_devices(n_workers * n_shards)
-    return jax.make_mesh((n_workers, n_shards), axes)
+    return auto_mesh(shape, axes)
 
 
 # Parameter-count thresholds steering worker granularity (see DESIGN.md §2/§4)

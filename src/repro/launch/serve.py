@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import ARCHS, ShapeConfig, get_arch, reduced
 from repro.data import SyntheticLM
+from repro.launch.mesh import worker_mesh
 from repro.launch.serving import build_serve_programs, serve_batch_specs
 
 
@@ -24,7 +25,7 @@ def serve_session(cfg, *, batch: int = 4, prompt_len: int = 32,
                   new_tokens: int = 16, seed: int = 0, mesh=None,
                   verbose: bool = True):
     """Returns (generated tokens (B, new_tokens), tokens/s)."""
-    mesh = mesh or jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = mesh or worker_mesh()
     cache_len = prompt_len + new_tokens
     shape = ShapeConfig(name="decode_32k", seq_len=cache_len,
                         global_batch=batch, kind="decode")

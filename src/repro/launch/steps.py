@@ -423,22 +423,23 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
     for a in shard_axes:
         n_shards *= mesh.shape[a]
     assert fs.shards == n_shards, (fs.shards, n_shards, shard_axes)
-    sharded = n_shards > 1
+    # a sharded plane runs its kernels shard-local under shard_map; so do
+    # the Pallas kernels of a replicated plane on more than one device,
+    # since pallas_call has no GSPMD partitioning rule (Mosaic refuses to
+    # be partitioned). The jnp path of a replicated plane stays GSPMD.
+    split = n_shards > 1 or (opt_cfg.use_pallas and mesh.devices.size > 1)
     p_sh = plane_sh
     s_sh = {k: (scalar_sh if k in SCALAR_STATE_KEYS else plane_sh)
             for k in abstract[1]}
 
-    # ---------------- shard-local kernel wrappers (n_shards > 1) --------- #
-    # pallas_call has no GSPMD partitioning rule, so the sharded plane runs
-    # the flat kernels shard-local under shard_map: each device sees its
-    # (R_local, plane_size/n_shards) sub-planes plus per-shard sidecar
-    # VIEWS (the sidecars are shard_map inputs sharded over the shard axes,
-    # i.e. slices indexed relative to the shard origin). Everything inside
-    # is elementwise or blocked within a shard, and shard boundaries land
-    # on tile/block boundaries, so shard-local bits == replicated bits.
-    if sharded:
-        from jax.experimental.shard_map import shard_map
-
+    # ---------------- device-local kernel wrappers (split planes) -------- #
+    # Each device sees its (R_local, plane_size/n_shards) sub-planes plus
+    # per-shard sidecar VIEWS (the sidecars are shard_map inputs sharded
+    # over the shard axes, i.e. slices indexed relative to the shard
+    # origin). Everything inside is elementwise or blocked within a shard,
+    # and shard boundaries land on tile/block boundaries, so shard-local
+    # bits == replicated bits.
+    if split:
         s_entry = _axes_entry(shard_axes)
         pspec = P(w_entry, s_entry)
         side_spec = P(s_entry, None)
@@ -455,10 +456,10 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
                                    (rnd.shape[0], _LANES)).reshape(-1)
             return flat_fused_update_ref(x, g, bs, bl, eta, extra, e16)
 
-        _upd_sharded = shard_map(
+        _upd_sharded = jax.shard_map(
             _upd_local, mesh=mesh,
             in_specs=(pspec, pspec, pspec, pspec, P(), P(), side_spec),
-            out_specs=(pspec, pspec), check_rep=False)
+            out_specs=(pspec, pspec), check_vma=False)
 
         def _enc_local(pp, bb, rp, rb, rndp):
             # shard-local [params ‖ B²] concat: the boundary sits at a
@@ -489,10 +490,10 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
             return (wire[..., :half], wire[..., half:],
                     nres[..., :half], nres[..., half:])
 
-        _enc_sharded = shard_map(
+        _enc_sharded = jax.shard_map(
             _enc_local, mesh=mesh,
             in_specs=(pspec, pspec, pspec, pspec, side_spec),
-            out_specs=(pspec, pspec, pspec, pspec), check_rep=False)
+            out_specs=(pspec, pspec, pspec, pspec), check_vma=False)
 
     def _expand_flat(base):
         params, state = _expand(base)
@@ -504,11 +505,12 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
         return _place(_draw(rng))
 
     def flat_sync_sharded(new_plane, new_state):
-        """Alg. 4 lines 11-12 with a sharded plane: the EF encode runs
-        shard-local, and the wire mean reduces over the WORKER axes only —
-        GSPMD all-reduces each device's sub-plane across its worker
+        """Alg. 4 lines 11-12 with a plane split across devices: the EF
+        encode runs shard-local, and the wire mean reduces over the WORKER
+        axes only — GSPMD all-reduces each device's sub-plane across its worker
         replicas while the shard (FSDP/TP) slots stay partitioned, so the
-        round moves per-shard wire bytes per device, not full-plane."""
+        round moves per-shard wire bytes per device, not full-plane. An
+        unsharded plane keeps the ONE collective over [params ‖ B²]."""
         b2 = new_state["b2_local"]
         if lossless:
             wire_p, wire_b = new_plane, b2
@@ -517,8 +519,13 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
             wire_p, wire_b, nres_p, nres_b = _enc_sharded(
                 new_plane, b2, new_state["res_params"],
                 new_state["res_b2"], jnp.asarray(enc_rnd_pw))
-        mean_p = mean_planes(wire_p, elems)        # worker-axes collective
-        mean_b = mean_planes(wire_b, None)
+        if n_shards == 1:
+            mean = mean_planes(jnp.concatenate([wire_p, wire_b], -1),
+                               sync_rnd_elems)
+            mean_p, mean_b = mean[..., :psize], mean[..., psize:]
+        else:
+            mean_p = mean_planes(wire_p, elems)    # worker-axes collective
+            mean_b = mean_planes(wire_b, None)
         out_state = {**new_state,
                      "tprime": jnp.zeros_like(new_state["tprime"]),
                      "b2_sync": mean_b, "b2_local": mean_b}
@@ -529,7 +536,7 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
 
     def flat_sync(new_plane, new_state):
         """Alg. 4 lines 11-12 over the packed payload — one wire array."""
-        if sharded:
+        if split:
             return flat_sync_sharded(new_plane, new_state)
         payload = jnp.concatenate([new_plane, new_state["b2_local"]], -1)
         new_res = None
@@ -588,7 +595,7 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
         tprime = fstate["tprime"] + 1
         eta = opt_lib.warmup_lr(opt_cfg.lr, step_no[0], opt_cfg.warmup_steps)
         extra = tprime[0].astype(jnp.float32) * opt_cfg.eps ** 2
-        if sharded:
+        if split:
             new_plane, new_b2 = _upd_sharded(
                 plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
                 eta, extra, jnp.asarray(upd_rnd_pw))

@@ -1,8 +1,9 @@
-"""End-to-end training driver (CPU-runnable, mesh-agnostic).
+"""End-to-end training driver over a mesh of all the devices JAX sees.
 
-Trains any architecture config (typically a ``--reduced`` variant on CPU)
-with any of the paper's optimizers on the synthetic non-IID LM stream,
-logging loss/PPL and the communication volume each algorithm actually moved.
+Trains any architecture config (a ``--reduced`` variant on the CPU; the
+published widths on a TPU chip, see ``chip_smoke.py``) with any of the
+paper's optimizers on the synthetic non-IID LM stream, logging loss/PPL and
+the communication volume each algorithm actually moved.
 
 The whole sync round is owned by one ``SyncEngine``
 (``core/sync_engine.py``) composing the schedule, the wire format, and the
@@ -52,24 +53,10 @@ from repro.core.codecs import CODEC_NAMES
 from repro.core.sync_engine import DRIFT_METRICS, make_sync_engine
 from repro.core.sync_policy import POLICY_NAMES
 from repro.data import SyntheticLM, make_train_batch
-from repro.launch.mesh import resolve_plan
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
 from repro.models.counting import count_params
-
-
-def make_cpu_mesh(n_workers: Optional[int] = None):
-    """(data, model) mesh over the host devices.
-
-    ``n_workers`` sizes the data (worker) axis; remaining devices go to the
-    model axis. Default (None) keeps the old behaviour: all devices on the
-    data axis. Requests that don't divide the device count fall back to that
-    default instead of silently being ignored (the old bug).
-    """
-    n = jax.device_count()
-    data = n if n_workers is None else max(1, min(n_workers, n))
-    if n % data:
-        data = n
-    return jax.make_mesh((data, n // data), ("data", "model"))
 
 
 @dataclasses.dataclass
@@ -115,7 +102,7 @@ def train_loop(cfg: ModelConfig, shape: ShapeConfig, opt_cfg: OptimizerConfig,
         # uninstrumented run's programs stay byte-identical (the emission
         # is absent, not skipped)
         opt_cfg = dataclasses.replace(opt_cfg, obs_metrics=True)
-    mesh = mesh or make_cpu_mesh()
+    mesh = mesh or worker_mesh()
     plan = plan or resolve_plan(cfg, mesh, optimizer=opt_cfg.name)
     with mesh:
         programs = build_train_programs(cfg, shape, opt_cfg, mesh, plan)
@@ -268,32 +255,25 @@ def train_loop(cfg: ModelConfig, shape: ShapeConfig, opt_cfg: OptimizerConfig,
         # measured means), and every local_step span carries the roofline-
         # optimal wall of its program. Costs one extra compile per program
         # (the AOT cache is separate from the loop's jit cache) — accepted
-        # under opt-in tracing; any lowering failure degrades to a trace
-        # without hlo_cost meta, which replay prices from warm means.
+        # under opt-in tracing. A lowering failure fails the traced run.
         hlo_local_s = hlo_extra_s = None
         if recorder is not None:
-            try:
-                from repro.roofline import region_table
-                bnp = make_train_batch(cfg, shape, ds, start_step,
-                                       n_workers=R if programs.is_local
-                                       else 0)
-                b0 = jax.tree_util.tree_map(jnp.asarray, bnp)
-                tabs = {}
-                for prog_key, prog_fn in (("local_step", programs.local_step),
-                                          ("sync_step", programs.sync_step)):
-                    txt = prog_fn.lower(params, opt_state,
-                                        b0).compile().as_text()
-                    tabs[prog_key] = region_table(
-                        txt, peak_flops=V5E.peak_flops, hbm_bw=V5E.hbm_bw)
-                recorder.meta["hlo_cost"] = {
-                    **tabs, "hw": {"peak_flops": V5E.peak_flops,
-                                   "hbm_bw": V5E.hbm_bw}}
-                hlo_local_s = float(tabs["local_step"]["optimal_s"])
-                hlo_extra_s = max(0.0, float(tabs["sync_step"]["optimal_s"])
-                                  - hlo_local_s)
-            except Exception as e:               # pragma: no cover - backend
-                if verbose:
-                    print(f"HLO cost attribution unavailable: {e}")
+            from repro.roofline import region_table
+            bnp = make_train_batch(cfg, shape, ds, start_step,
+                                   n_workers=R if programs.is_local else 0)
+            b0 = jax.tree_util.tree_map(jnp.asarray, bnp)
+            tabs = {}
+            for prog_key, prog_fn in (("local_step", programs.local_step),
+                                      ("sync_step", programs.sync_step)):
+                txt = prog_fn.lower(params, opt_state, b0).compile().as_text()
+                tabs[prog_key] = region_table(
+                    txt, peak_flops=V5E.peak_flops, hbm_bw=V5E.hbm_bw)
+            recorder.meta["hlo_cost"] = {
+                **tabs, "hw": {"peak_flops": V5E.peak_flops,
+                               "hbm_bw": V5E.hbm_bw}}
+            hlo_local_s = float(tabs["local_step"]["optimal_s"])
+            hlo_extra_s = max(0.0, float(tabs["sync_step"]["optimal_s"])
+                              - hlo_local_s)
 
         losses, ppls = [], []
         t0 = time.perf_counter()
@@ -512,12 +492,12 @@ def main() -> None:
                          "snapshot next to it (OUT.prom)")
     ap.add_argument("--workers", type=int, default=0, metavar="N",
                     help="size of the mesh's data (worker) axis; remaining "
-                         "host devices form the model axis, which a --flat "
+                         "devices form the model axis, which a --flat "
                          "run uses to FSDP/TP-shard each worker's plane "
                          "(sharded sub-planes, per-shard sync payload). "
-                         "0 -> all devices on the worker axis. Pair with "
+                         "0 -> all devices on the worker axis. On the CPU, "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=K "
-                         "to simulate K CPU devices")
+                         "gives K virtual devices")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--iid", action="store_true", help="disable non-IID workers")
@@ -542,7 +522,8 @@ def main() -> None:
     sched = (f"H={args.H}" if args.sync_policy == "fixed_h" else
              f"adaptive(thr={args.sync_threshold}, "
              f"h=[{args.h_min},{args.h_max or 4 * args.H}])")
-    mesh = make_cpu_mesh(args.workers or None)
+    enable_compile_cache()
+    mesh = worker_mesh(args.workers or None)
     print(f"training {cfg.name} ({count_params(cfg):,} params) with "
           f"{args.optimizer} {sched}"
           f"{' +' + args.compress + ' sync' if args.compress else ''} "

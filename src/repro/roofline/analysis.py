@@ -242,15 +242,9 @@ def analyze(compiled, *, arch: str, shape_name: str, mesh_name: str,
     counts = {k: v for k, v in c.coll_counts.items()}
     wire = collective_wire_bytes(col)
 
-    mem = None
-    try:
-        ma = compiled.memory_analysis()
-        mem = float(getattr(ma, "temp_size_in_bytes", 0)
-                    + getattr(ma, "argument_size_in_bytes", 0)
-                    + getattr(ma, "output_size_in_bytes", 0)
-                    - getattr(ma, "alias_size_in_bytes", 0))
-    except Exception:
-        pass
+    ma = compiled.memory_analysis()
+    mem = float(ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
 
     rep = RooflineReport(
         arch=arch, shape=shape_name, mesh=mesh_name, n_chips=n_chips,

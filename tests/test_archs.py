@@ -14,7 +14,7 @@ from repro.configs import (ARCHS, OptimizerConfig, ShapeConfig, get_arch,
                            reduced)
 from repro.data import SyntheticLM, make_train_batch
 from repro.launch.steps import build_train_programs
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.models import build_model
 
 SEQ, BATCH, VOCAB = 64, 4, 512
@@ -51,7 +51,7 @@ def test_forward_shapes_and_finiteness(arch):
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_one_train_step(arch):
     cfg = reduced(get_arch(arch), vocab=VOCAB)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = worker_mesh()
     opt_cfg = OptimizerConfig(name="local_adaalter", lr=0.3, H=2,
                               warmup_steps=0)
     with mesh:

@@ -147,12 +147,12 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import OptimizerConfig, ShapeConfig, get_arch, reduced
 from repro.configs.base import SyncConfig
 from repro.data import SyntheticLM, make_train_batch
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
 
 CFG = reduced(get_arch("biglstm"), vocab=128)
 SHAPE = ShapeConfig(name="t", seq_len=16, global_batch=4, kind="train")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = worker_mesh(2)
 
 def run(opt_cfg, plan):
     with mesh:
@@ -203,6 +203,7 @@ import numpy as np
 import jax
 from repro.configs import OptimizerConfig, ShapeConfig, get_arch, reduced
 from repro.configs.base import SyncConfig
+from repro.launch.mesh import worker_mesh
 from repro.launch.train import train_loop
 
 CFG = reduced(get_arch("biglstm"), vocab=128)
@@ -212,8 +213,8 @@ OPT = OptimizerConfig.from_sync(
                threshold=0.02, h_min=2, h_max=8),
     name="local_adaalter", lr=0.5, H=4, warmup_steps=2,
     use_pallas=True, flat=True)
-small = jax.make_mesh((1, 1), ("data", "model"))
-big = jax.make_mesh((2, 2), ("data", "model"))
+small = worker_mesh(1, devices=jax.devices()[:1])
+big = worker_mesh(2)
 with tempfile.TemporaryDirectory() as d:
     r1 = train_loop(CFG, SHAPE, OPT, steps=3, mesh=small, checkpoint_dir=d,
                     checkpoint_every=3, verbose=False)

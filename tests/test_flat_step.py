@@ -18,9 +18,9 @@ import pytest
 from repro.configs import OptimizerConfig, ShapeConfig, get_arch, reduced
 from repro.configs.base import SyncConfig
 from repro.data import SyntheticLM, make_train_batch
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
-from repro.launch.train import make_cpu_mesh, train_loop
+from repro.launch.train import train_loop
 
 CFG = reduced(get_arch("biglstm"), vocab=128)
 SHAPE = ShapeConfig(name="t", seq_len=16, global_batch=4, kind="train")
@@ -53,7 +53,7 @@ def _assert_tree_bitwise(a, b, what=""):
     ("bf16", False),        # elementwise wire truncation
 ])
 def test_flat_step_bitwise_matches_per_leaf(compression, use_pallas):
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     with mesh:
         plan = resolve_plan(CFG, mesh, optimizer="local_adaalter")
         pL = build_train_programs(CFG, SHAPE, _opt(False, compression,
@@ -90,7 +90,7 @@ def test_flat_step_bitwise_matches_per_leaf(compression, use_pallas):
 
 
 def test_flat_requires_local_adaalter():
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     with mesh:
         plan = resolve_plan(CFG, mesh, optimizer="local_sgd")
         with pytest.raises(ValueError, match="flat"):
@@ -100,7 +100,7 @@ def test_flat_requires_local_adaalter():
 
 
 def test_flat_requires_positive_eps():
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     with mesh:
         plan = resolve_plan(CFG, mesh, optimizer="local_adaalter")
         with pytest.raises(ValueError, match="eps"):
@@ -134,7 +134,7 @@ def test_checkpoint_cross_layout_bitwise(tmp_path):
     assert a.sync_steps == b.sync_steps
     # the step-6 checkpoints (one per-leaf, one packed planes) hold the
     # same bits
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     from repro.checkpoint import restore_checkpoint
     from repro.core.sync_engine import SyncState
     with mesh:

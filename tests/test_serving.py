@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ShapeConfig, get_arch, get_shape, reduced
+from repro.launch.mesh import worker_mesh
 from repro.launch.serving import (build_serve_programs, cache_geometry,
                                   decode_cache_specs, serve_batch_specs)
 from repro.models import build_model
@@ -17,7 +18,7 @@ DECODE_FAMS = ["qwen2-7b", "mamba2-370m", "hymba-1.5b",
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return worker_mesh()
 
 
 @pytest.mark.parametrize("arch", DECODE_FAMS)

@@ -17,8 +17,8 @@ from repro.models import build_model
 from repro.sharding.partition import ShardingRules
 from repro.sharding.specs import param_shardings, shape_safe_spec
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-POD_MESH = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(cfg, plan, mesh, with_workers):
@@ -104,6 +104,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.configs import OptimizerConfig, ShapeConfig, get_arch, reduced
 from repro.configs.base import ParallelismPlan
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import build_train_programs
 from repro.data import SyntheticLM, make_train_batch
 
@@ -113,7 +114,7 @@ shape = ShapeConfig(name="t", seq_len=32, global_batch=8, kind="train")
 opt_cfg = OptimizerConfig(name="local_adaalter", lr=0.3, H=2, warmup_steps=0)
 
 def run(mesh_shape, axes, plan):
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = auto_mesh(mesh_shape, axes)
     with mesh:
         pr = build_train_programs(cfg, shape, opt_cfg, mesh, plan)
         params, state = pr.init_fn(jax.random.PRNGKey(0))

@@ -17,9 +17,9 @@ from repro.core.sync_engine import (DRIFT_METRICS, SyncEngine, SyncState,
 from repro.core.sync_policy import AdaptiveSyncPolicy, FixedHPolicy
 from repro.core import optimizers as opt_lib
 from repro.data import SyntheticLM, make_train_batch
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
-from repro.launch.train import make_cpu_mesh, train_loop
+from repro.launch.train import train_loop
 
 SHAPE = ShapeConfig(name="eng", seq_len=32, global_batch=8, kind="train")
 
@@ -230,7 +230,7 @@ def test_legacy_two_tuple_checkpoint_still_restores(tmp_path):
     fallback path; the adaptive window then re-anchors at the restore."""
     cfg = _cfg()
     opt = OptimizerConfig(name="local_adaalter", lr=0.5, H=4, warmup_steps=5)
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     plan = resolve_plan(cfg, mesh, optimizer=opt.name)
     with mesh:
         programs = build_train_programs(cfg, SHAPE, opt, mesh, plan)
@@ -282,7 +282,7 @@ def test_make_optimizer_adds_anchor_only_for_staleness():
 
 def _run_program_steps(opt):
     cfg = _cfg()
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     plan = resolve_plan(cfg, mesh, optimizer=opt.name)
     with mesh:
         programs = build_train_programs(cfg, SHAPE, opt, mesh, plan)

@@ -11,9 +11,9 @@ from repro.core.comm import sync_payload_bytes
 from repro.core.sync_policy import (AdaptiveSyncPolicy, FixedHPolicy,
                                     make_sync_policy)
 from repro.data import SyntheticLM, make_train_batch
-from repro.launch.mesh import resolve_plan
+from repro.launch.mesh import resolve_plan, worker_mesh
 from repro.launch.steps import build_train_programs
-from repro.launch.train import make_cpu_mesh, train_loop
+from repro.launch.train import train_loop
 
 SHAPE = ShapeConfig(name="pol", seq_len=32, global_batch=8, kind="train")
 
@@ -107,7 +107,7 @@ def test_make_sync_policy_defaults():
 # --------------------------------------------------------------------------- #
 def _manual_modulo_loop(cfg, shape, opt_cfg, steps, seed=0):
     """The historical train loop: sync iff (step+1) % H == 0."""
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     plan = resolve_plan(cfg, mesh, optimizer=opt_cfg.name)
     with mesh:
         programs = build_train_programs(cfg, shape, opt_cfg, mesh, plan)
@@ -198,7 +198,7 @@ def test_adaptive_end_to_end_respects_bounds():
 
 def _step_metrics(opt):
     cfg = _cfg()
-    mesh = make_cpu_mesh()
+    mesh = worker_mesh()
     plan = resolve_plan(cfg, mesh, optimizer=opt.name)
     with mesh:
         programs = build_train_programs(cfg, SHAPE, opt, mesh, plan)
