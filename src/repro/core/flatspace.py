@@ -338,6 +338,7 @@ def is_flat_checkpoint(keys) -> bool:
 # --------------------------------------------------------------------------- #
 # the single-collective sync mean
 # --------------------------------------------------------------------------- #
+@jax.named_scope("worker_mean")
 def mean_planes(plane, round16_elems):
     """Cross-worker mean of one wire plane — the ONE collective of a flat
     sync round — bitwise identical to the per-leaf means.

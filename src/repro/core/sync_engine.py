@@ -91,6 +91,7 @@ jax.tree_util.register_dataclass(
 # --------------------------------------------------------------------------- #
 # device-side: error-feedback encode of one payload pytree
 # --------------------------------------------------------------------------- #
+@jax.named_scope("ef_encode")
 def ef_apply(tree: Pytree, residual: Pytree, codec: WireCodec,
              batch_ndim: int, *, clamp_nonneg: bool = False
              ) -> Tuple[Pytree, Pytree]:

@@ -80,6 +80,7 @@ def _batch_sharding(rules: ShardingRules, batch_tree, *, workers: bool):
     return jax.tree_util.tree_map(one, batch_tree)
 
 
+@jax.named_scope("worker_mean")
 def _mean_over_workers(tree):
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(jnp.mean(x, axis=0, keepdims=True), x.shape),
@@ -239,31 +240,34 @@ def build_train_programs(cfg: ModelConfig, shape: ShapeConfig,
 
         def step(params, opt_state, batch, *, do_sync: bool):
             loss, metrics, grads = vworker(params, batch)
-            if opt_cfg.use_pallas and opt_cfg.name == "local_adaalter":
-                from repro.kernels.ops import tree_fused_update
-                # the fused kernel bypasses opt.local_step, so the grad_clip
-                # wrapper never sees these grads — clip per worker here.
-                # `grads` itself stays RAW: the drift statistics below must
-                # see the same values the non-Pallas path's stat sees (there
-                # the wrapper clips inside opt.local_step, after the stat's
-                # inputs are captured).
-                applied = grads
-                if opt_cfg.grad_clip > 0:
-                    applied, _ = opt_lib.clip_by_global_norm(
-                        grads, opt_cfg.grad_clip, batch_ndim=1)
-                step_no = opt_state["step"] + 1
-                tprime = opt_state["tprime"] + 1
-                eta = opt_lib.warmup_lr(opt_cfg.lr, step_no[0], opt_cfg.warmup_steps)
-                extra = tprime[0].astype(jnp.float32) * opt_cfg.eps ** 2
-                new_params, new_b2 = tree_fused_update(
-                    params, applied, opt_state["b2_sync"], opt_state["b2_local"],
-                    eta, extra, use_pallas=True)
-                # keep extra leaves (e.g. compressed_sync's error-feedback
-                # residuals) instead of rebuilding the dict from scratch
-                new_state = {**opt_state, "step": step_no, "tprime": tprime,
-                             "b2_local": new_b2}
-            else:
-                new_params, new_state = vlocal(grads, opt_state, params)
+            with jax.named_scope("adaalter_update"):
+                if opt_cfg.use_pallas and opt_cfg.name == "local_adaalter":
+                    from repro.kernels.ops import tree_fused_update
+                    # the fused kernel bypasses opt.local_step, so the
+                    # grad_clip wrapper never sees these grads — clip per
+                    # worker here. `grads` itself stays RAW: the drift
+                    # statistics below must see the same values the
+                    # non-Pallas path's stat sees (there the wrapper clips
+                    # inside opt.local_step, after the stat's inputs are
+                    # captured).
+                    applied = grads
+                    if opt_cfg.grad_clip > 0:
+                        applied, _ = opt_lib.clip_by_global_norm(
+                            grads, opt_cfg.grad_clip, batch_ndim=1)
+                    step_no = opt_state["step"] + 1
+                    tprime = opt_state["tprime"] + 1
+                    eta = opt_lib.warmup_lr(opt_cfg.lr, step_no[0],
+                                            opt_cfg.warmup_steps)
+                    extra = tprime[0].astype(jnp.float32) * opt_cfg.eps ** 2
+                    new_params, new_b2 = tree_fused_update(
+                        params, applied, opt_state["b2_sync"],
+                        opt_state["b2_local"], eta, extra, use_pallas=True)
+                    # keep extra leaves (e.g. compressed_sync's error-
+                    # feedback residuals) instead of rebuilding the dict
+                    new_state = {**opt_state, "step": step_no,
+                                 "tprime": tprime, "b2_local": new_b2}
+                else:
+                    new_params, new_state = vlocal(grads, opt_state, params)
             out_metrics = {"loss": jnp.mean(loss),
                            **{k: jnp.mean(v) for k, v in metrics.items()}}
             if opt_cfg.obs_metrics:
@@ -287,8 +291,9 @@ def build_train_programs(cfg: ModelConfig, shape: ShapeConfig,
             elif stat is not None:
                 out_metrics["drift"] = _drift_stat(new_params, params)
             if do_sync:
-                new_params, new_state = opt.sync(new_params, new_state,
-                                                 _mean_over_workers)
+                with jax.named_scope("sync"):
+                    new_params, new_state = opt.sync(new_params, new_state,
+                                                     _mean_over_workers)
                 if staleness:
                     # re-anchor the staleness statistic at THIS round's
                     # per-worker gradients (the one place they're in scope)
@@ -322,8 +327,7 @@ def build_train_programs(cfg: ModelConfig, shape: ShapeConfig,
         out_shardings=(p_sh, s_sh, None),
         donate_argnums=(0, 1),
     )
-    local_step = jax.jit(partial(step, do_sync=False), **common)
-    sync_step = jax.jit(partial(step, do_sync=True), **common)
+    local_step, sync_step = _jit_steps(step, **common)
 
     # ---------------- flat-plane rebuild (OptimizerConfig.flat) ----------- #
     flat_fields = {}
@@ -516,9 +520,10 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
             wire_p, wire_b = new_plane, b2
             nres_p = nres_b = None
         else:
-            wire_p, wire_b, nres_p, nres_b = _enc_sharded(
-                new_plane, b2, new_state["res_params"],
-                new_state["res_b2"], jnp.asarray(enc_rnd_pw))
+            with jax.named_scope("ef_encode"):
+                wire_p, wire_b, nres_p, nres_b = _enc_sharded(
+                    new_plane, b2, new_state["res_params"],
+                    new_state["res_b2"], jnp.asarray(enc_rnd_pw))
         if n_shards == 1:
             mean = mean_planes(jnp.concatenate([wire_p, wire_b], -1),
                                sync_rnd_elems)
@@ -544,23 +549,26 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
             wire = payload
         elif sync_cfg.compression == "int8":
             from repro.kernels.sync_fused import flat_ef_plane
-            res = jnp.concatenate([new_state["res_params"],
-                                   new_state["res_b2"]], -1)
-            wire, new_res = flat_ef_plane(
-                payload, res, sync_rnd_blocks, sync_low_blocks, block=block,
-                use_pallas=opt_cfg.use_pallas, fused=sync_cfg.fused)
+            with jax.named_scope("ef_encode"):
+                res = jnp.concatenate([new_state["res_params"],
+                                       new_state["res_b2"]], -1)
+                wire, new_res = flat_ef_plane(
+                    payload, res, sync_rnd_blocks, sync_low_blocks,
+                    block=block, use_pallas=opt_cfg.use_pallas,
+                    fused=sync_cfg.fused)
         else:   # bf16 wire: elementwise EF roundtrip, same bits per leaf
             from repro.kernels.tiling import round_through_bf16
-            res = jnp.concatenate([new_state["res_params"],
-                                   new_state["res_b2"]], -1)
-            v = payload + res
-            # the codec truncates EVERY payload (B² included); the wire
-            # cast then re-rounds only the bf16 param slots (a no-op)
-            vq = jnp.maximum(round_through_bf16(v),
-                             jnp.asarray(sync_low_elems))
-            wire = jnp.where(jnp.asarray(sync_rnd_elems),
-                             round_through_bf16(vq), vq)
-            new_res = v - wire
+            with jax.named_scope("ef_encode"):
+                res = jnp.concatenate([new_state["res_params"],
+                                       new_state["res_b2"]], -1)
+                v = payload + res
+                # the codec truncates EVERY payload (B² included); the wire
+                # cast then re-rounds only the bf16 param slots (a no-op)
+                vq = jnp.maximum(round_through_bf16(v),
+                                 jnp.asarray(sync_low_elems))
+                wire = jnp.where(jnp.asarray(sync_rnd_elems),
+                                 round_through_bf16(vq), vq)
+                new_res = v - wire
         mean = mean_planes(wire, sync_rnd_elems)       # the ONE collective
         b2m = mean[..., psize:]
         out_state = {**new_state,
@@ -591,25 +599,27 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
         g_plane = (a_plane if (not staleness or opt_cfg.grad_clip <= 0)
                    else jax.lax.with_sharding_constraint(fs.pack(grads),
                                                          plane_sh))
-        step_no = fstate["step"] + 1
-        tprime = fstate["tprime"] + 1
-        eta = opt_lib.warmup_lr(opt_cfg.lr, step_no[0], opt_cfg.warmup_steps)
-        extra = tprime[0].astype(jnp.float32) * opt_cfg.eps ** 2
-        if split:
-            new_plane, new_b2 = _upd_sharded(
-                plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
-                eta, extra, jnp.asarray(upd_rnd_pw))
-        elif opt_cfg.use_pallas:
-            from repro.kernels.adaalter_update import flat_fused_update
-            new_plane, new_b2 = flat_fused_update(
-                plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
-                eta, extra, jnp.asarray(upd_rnd_rows),
-                interpret=not on_tpu())
-        else:
-            from repro.kernels.ref import flat_fused_update_ref
-            new_plane, new_b2 = flat_fused_update_ref(
-                plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
-                eta, extra, jnp.asarray(elems))
+        with jax.named_scope("adaalter_update"):
+            step_no = fstate["step"] + 1
+            tprime = fstate["tprime"] + 1
+            eta = opt_lib.warmup_lr(opt_cfg.lr, step_no[0],
+                                    opt_cfg.warmup_steps)
+            extra = tprime[0].astype(jnp.float32) * opt_cfg.eps ** 2
+            if split:
+                new_plane, new_b2 = _upd_sharded(
+                    plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
+                    eta, extra, jnp.asarray(upd_rnd_pw))
+            elif opt_cfg.use_pallas:
+                from repro.kernels.adaalter_update import flat_fused_update
+                new_plane, new_b2 = flat_fused_update(
+                    plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
+                    eta, extra, jnp.asarray(upd_rnd_rows),
+                    interpret=not on_tpu())
+            else:
+                from repro.kernels.ref import flat_fused_update_ref
+                new_plane, new_b2 = flat_fused_update_ref(
+                    plane, a_plane, fstate["b2_sync"], fstate["b2_local"],
+                    eta, extra, jnp.asarray(elems))
         new_state = {**fstate, "step": step_no, "tprime": tprime,
                      "b2_local": new_b2}
         out_metrics = {"loss": jnp.mean(loss),
@@ -627,7 +637,8 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
             pn = jnp.sqrt(jnp.sum(jnp.square(plane), -1))
             out_metrics["drift"] = jnp.mean(d / (pn + 1e-12))
         if do_sync:
-            new_plane, new_state = flat_sync(new_plane, new_state)
+            with jax.named_scope("sync"):
+                new_plane, new_state = flat_sync(new_plane, new_state)
             if staleness:
                 new_state = {**new_state, "g_anchor": g_plane}
         return new_plane, new_state, out_metrics
@@ -635,8 +646,19 @@ def _flat_programs(fs, opt_cfg: OptimizerConfig, mesh, plan, R: int,
     common = dict(in_shardings=(p_sh, s_sh, b_sh),
                   out_shardings=(p_sh, s_sh, None),
                   donate_argnums=(0, 1))
-    return (init_fn, jax.jit(partial(step, do_sync=False), **common),
-            jax.jit(partial(step, do_sync=True), **common), p_sh, s_sh)
+    return (init_fn, *_jit_steps(step, **common), p_sh, s_sh)
+
+
+def _jit_steps(step, **jit_kw):
+    """The two jitted step programs of ``step(..., do_sync=...)``, named
+    ``local_step`` and ``sync_step``: a profiler trace names them
+    ``jit_local_step`` and ``jit_sync_step`` in its ``XLA Modules``."""
+    programs = []
+    for name, do_sync in (("local_step", False), ("sync_step", True)):
+        fn = partial(step, do_sync=do_sync)
+        fn.__name__ = name
+        programs.append(jax.jit(fn, **jit_kw))
+    return programs
 
 
 # --------------------------------------------------------------------------- #

@@ -18,7 +18,10 @@ single-HBM-pass Pallas kernel unless ``--unfused-sync``. Checkpoints carry
 the engine's ``SyncState`` (drift accumulator + window position) next to
 ``(params, opt_state)``, so a mid-window restore resumes the exact adaptive
 schedule. ``TrainResult`` reports the *measured* sync count/steps and the
-comm bytes they moved, not the static ``2P/H`` formula. ``--trace out.json``
+comm bytes they moved, not the static ``2P/H`` formula. The loop's host
+spans (``train.step`` and its children) land in any ``jax.profiler`` trace
+of a run, beside the step programs ``jit_local_step``/``jit_sync_step``
+and their named scopes (``repro.trace.scopes``). ``--trace out.json``
 additionally records the run as a per-worker span timeline (``repro.trace``)
 — the engine's actual sync decisions plus modeled device/wire round costs —
 for Perfetto viewing and trace-driven what-if replay. ``--metrics out.jsonl``
@@ -84,12 +87,21 @@ def train_loop(cfg: ModelConfig, shape: ShapeConfig, opt_cfg: OptimizerConfig,
                non_iid: bool = True, checkpoint_dir: str = "",
                checkpoint_every: int = 0, verbose: bool = True,
                trace_out: str = "", metrics_out: str = "") -> TrainResult:
-    """``trace_out`` records the run as a span stream (``repro.trace``):
+    """Every step runs inside a ``jax.profiler.StepTraceAnnotation``
+    named ``train.step`` (argument ``kind``: local or sync), whose children
+    ``train.batch_build``, ``train.transfer``, ``train.dispatch``,
+    ``train.loss_read``, ``train.observe`` and, where they run,
+    ``train.log`` and ``train.ckpt`` mark the host's work
+    (``repro.trace.events.HostSpans``). They land in any ``jax.profiler``
+    trace of the run, on the device trace's clock; with no profiler
+    running they cost one inactive check each.
+
+    ``trace_out`` records the run as a span stream (``repro.trace``):
     one timeline row per worker per step carrying the sync decisions the
     engine actually took, plus modeled device/wire costs on sync rounds —
     the input of the what-if replay engine and the Chrome/Perfetto export.
-    All host times (including ``wall_s``) share the monotonic
-    ``time.perf_counter`` clock.
+    Its host spans take their boundaries from the same ``with`` blocks, on
+    the recorder's ``time.perf_counter`` clock (``wall_s`` reads it too).
 
     ``metrics_out`` streams the run's health metrics (``repro.obs``): one
     JSONL row per step — loss, grad norm, drift, B² quantiles per dtype
@@ -276,89 +288,99 @@ def train_loop(cfg: ModelConfig, shape: ShapeConfig, opt_cfg: OptimizerConfig,
                               - hlo_local_s)
 
         losses, ppls = [], []
+        from repro.trace.events import HostSpans, health_span_args
+        spans = HostSpans(recorder)
         t0 = time.perf_counter()
         for step in range(start_step, steps):
-            batch_np = make_train_batch(cfg, shape, ds, step,
-                                        n_workers=R if programs.is_local else 0)
-            batch = jax.tree_util.tree_map(jnp.asarray, batch_np)
             do_sync = engine.want_sync(step)
-            t_step = (recorder.now() if recorder is not None
-                      else time.perf_counter() if registry else 0.0)
-            fn = programs.sync_step if do_sync else programs.local_step
-            params, opt_state, metrics = fn(params, opt_state, batch)
-            # the blocking metric read keeps the device work inside the span
-            loss = float(metrics["loss"])
-            drift_val = (float(metrics.get("drift", 0.0))
-                         if engine.wants_drift else 0.0)
-            # decision-time window state (before observe folds this step in)
-            st = engine.export_state() if recorder is not None else None
-            engine.observe(step, do_sync,
-                           {"drift": drift_val}
-                           if engine.wants_drift else None)
-            # ONE health summary feeds both exports (same numbers on the
-            # trace spans and in the metrics rows, by construction)
-            summary = (probe.step_summary(opt_state, metrics,
-                                          synced=do_sync)
-                       if probe is not None else {})
-            if recorder is not None:
-                from repro.trace.events import health_span_args
-                dur = recorder.now() - t_step
-                t_end = t_step + dur
-                health = health_span_args(summary)
-                if hlo_local_s is not None:
-                    health["hlo_optimal_s"] = hlo_local_s
-                for w in range(R):
-                    recorder.add("local_step", worker=w, step=step,
-                                 t0=t_step, dur=dur, synced=do_sync,
-                                 loss=loss, drift=drift_val,
-                                 sync_since=int(st.since),
-                                 sync_drift=float(st.drift), **health)
-                    if do_sync:
-                        enc_args = {}
-                        if hlo_extra_s is not None:
-                            enc_args["hlo_extra_optimal_s"] = hlo_extra_s
-                        recorder.add("ef_encode", worker=w, step=step,
-                                     t0=t_end, dur=enc_t, modeled=True,
-                                     hbm_bytes=enc_bytes,
-                                     codec=engine.codec.name, **enc_args)
-                        recorder.add("collective", worker=w, step=step,
-                                     t0=t_end + enc_t, dur=wire_t,
-                                     modeled=True, wire_bytes=round_b,
-                                     wire_bytes_per_shard=shard_b,
-                                     n_shards=programs.n_shards,
-                                     n_collectives=n_coll,
-                                     codec=engine.codec.name, workers=R)
-            if registry:
-                step_dur = (dur if recorder is not None
-                            else time.perf_counter() - t_step)
-                registry.counter("steps_total").inc()
-                registry.gauge("loss",
-                               help="train loss (mean over workers)"
-                               ).set(loss)
-                registry.histogram("step_time_s",
-                                   help="host wall of one train step"
-                                   ).observe(step_dur)
-                probe.record(registry, summary, step=step, synced=do_sync)
-                registry.collect(step)
-            losses.append(loss)
-            ppls.append(math.exp(min(loss, 30.0)))
-            if verbose and (step % log_every == 0 or step == steps - 1):
-                t_ev = recorder.now() if recorder is not None else 0.0
-                print(f"step {step:5d} loss {loss:8.4f} ppl {ppls[-1]:10.2f} "
-                      f"{'sync' if do_sync else 'local'}")
+            with spans.step(step, kind="sync" if do_sync else "local"):
+                with spans("batch_build"):
+                    batch_np = make_train_batch(
+                        cfg, shape, ds, step,
+                        n_workers=R if programs.is_local else 0)
+                with spans("transfer"):
+                    batch = jax.tree_util.tree_map(jnp.asarray, batch_np)
+                fn = programs.sync_step if do_sync else programs.local_step
+                with spans("dispatch"):
+                    params, opt_state, metrics = fn(params, opt_state, batch)
+                # the blocking metric read keeps the device work inside the
+                # step's host span
+                with spans("loss_read"):
+                    loss = float(metrics["loss"])
+                    drift_val = (float(metrics.get("drift", 0.0))
+                                 if engine.wants_drift else 0.0)
+                with spans("observe"):
+                    # decision-time window state (before observe folds
+                    # this step in)
+                    st = (engine.export_state() if recorder is not None
+                          else None)
+                    engine.observe(step, do_sync,
+                                   {"drift": drift_val}
+                                   if engine.wants_drift else None)
+                    # ONE health summary feeds both exports (same numbers
+                    # on the trace spans and in the metrics rows)
+                    summary = (probe.step_summary(opt_state, metrics,
+                                                  synced=do_sync)
+                               if probe is not None else {})
+                # the recorder's step span: dispatch through observe
+                t_step, dur = spans.since("dispatch")
                 if recorder is not None:
-                    recorder.add("eval", step=step, t0=t_ev,
-                                 dur=recorder.now() - t_ev, loss=loss)
-            if checkpoint_dir and checkpoint_every and \
-                    (step + 1) % checkpoint_every == 0:
-                from repro.checkpoint import save_checkpoint
-                t_ck = recorder.now() if recorder is not None else 0.0
-                save_checkpoint(checkpoint_dir, step + 1,
-                                (params, opt_state, engine.export_state()))
-                if recorder is not None:
-                    recorder.add("ckpt", step=step, t0=t_ck,
-                                 dur=recorder.now() - t_ck,
-                                 dir=checkpoint_dir)
+                    t_end = t_step + dur
+                    health = health_span_args(summary)
+                    if hlo_local_s is not None:
+                        health["hlo_optimal_s"] = hlo_local_s
+                    for w in range(R):
+                        recorder.add("local_step", worker=w, step=step,
+                                     t0=t_step, dur=dur, synced=do_sync,
+                                     loss=loss, drift=drift_val,
+                                     sync_since=int(st.since),
+                                     sync_drift=float(st.drift), **health)
+                        if do_sync:
+                            enc_args = {}
+                            if hlo_extra_s is not None:
+                                enc_args["hlo_extra_optimal_s"] = hlo_extra_s
+                            recorder.add("ef_encode", worker=w, step=step,
+                                         t0=t_end, dur=enc_t, modeled=True,
+                                         hbm_bytes=enc_bytes,
+                                         codec=engine.codec.name, **enc_args)
+                            recorder.add("collective", worker=w, step=step,
+                                         t0=t_end + enc_t, dur=wire_t,
+                                         modeled=True, wire_bytes=round_b,
+                                         wire_bytes_per_shard=shard_b,
+                                         n_shards=programs.n_shards,
+                                         n_collectives=n_coll,
+                                         codec=engine.codec.name, workers=R)
+                losses.append(loss)
+                ppls.append(math.exp(min(loss, 30.0)))
+                echo = verbose and (step % log_every == 0
+                                    or step == steps - 1)
+                if registry or echo:
+                    with spans("log", record="eval" if echo else None,
+                               step=step, loss=loss):
+                        if registry:
+                            registry.counter("steps_total").inc()
+                            registry.gauge(
+                                "loss", help="train loss (mean over workers)"
+                            ).set(loss)
+                            registry.histogram(
+                                "step_time_s",
+                                help="host wall of one train step"
+                            ).observe(dur)
+                            probe.record(registry, summary, step=step,
+                                         synced=do_sync)
+                            registry.collect(step)
+                        if echo:
+                            print(f"step {step:5d} loss {loss:8.4f} ppl "
+                                  f"{ppls[-1]:10.2f} "
+                                  f"{'sync' if do_sync else 'local'}")
+                if checkpoint_dir and checkpoint_every and \
+                        (step + 1) % checkpoint_every == 0:
+                    from repro.checkpoint import save_checkpoint
+                    with spans("ckpt", record="ckpt", step=step,
+                               dir=checkpoint_dir):
+                        save_checkpoint(checkpoint_dir, step + 1,
+                                        (params, opt_state,
+                                         engine.export_state()))
 
         wall = time.perf_counter() - t0
         executed = max(steps - start_step, 0)

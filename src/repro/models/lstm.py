@@ -54,7 +54,8 @@ def init_lstm_state(cfg, batch, dtype=jnp.float32):
 def lstm_logits(params, tokens, cfg, *, rng=None, dropout_rate: float = 0.0):
     """tokens: (B,S) -> logits (B,S,V)."""
     b, s = tokens.shape
-    x = params["embed"][tokens]                            # (B,S,P)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                        # (B,S,P)
     deterministic = rng is None or dropout_rate == 0.0
     if not deterministic:
         rng_layers = jax.random.split(rng, cfg.n_layers + 1)
@@ -68,12 +69,14 @@ def lstm_logits(params, tokens, cfg, *, rng=None, dropout_rate: float = 0.0):
             hp, c = carry
             hp, c = _cell(cell, xt, hp, c)
             return (hp, c), hp
-        _, ys = jax.lax.scan(step, state[li], xs)
-        if not deterministic:
-            ys = dropout(rng_layers[li], ys, dropout_rate, False)
-        xs = ys + xs if li > 0 else ys                     # residual after first layer
+        with jax.named_scope("lstmp"):
+            _, ys = jax.lax.scan(step, state[li], xs)
+            if not deterministic:
+                ys = dropout(rng_layers[li], ys, dropout_rate, False)
+            xs = ys + xs if li > 0 else ys                 # residual after first layer
     out = xs.transpose(1, 0, 2)                            # (B,S,P)
-    return out @ params["head_w"] + params["head_b"]
+    with jax.named_scope("head"):
+        return out @ params["head_w"] + params["head_b"]
 
 
 def lstm_hidden_step(params, token, state, cfg):

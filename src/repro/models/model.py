@@ -27,6 +27,7 @@ def _compute_dtype(cfg):
 
 
 # --------------------------------------------------------------------------- #
+@jax.named_scope("xent")
 def softmax_xent(logits, labels, mask=None):
     """Mean token cross-entropy in fp32. logits: (B,S,V), labels: (B,S)."""
     logits = logits.astype(jnp.float32)
@@ -49,6 +50,7 @@ def fused_softmax_xent(logits, labels):
     return nll
 
 
+@jax.named_scope("xent")
 def _fused_xent_fwd_impl(logits, labels):
     x = logits.astype(jnp.float32)
     m = jnp.max(x, axis=-1, keepdims=True)
@@ -67,6 +69,7 @@ def _fused_xent_fwd(logits, labels):
     return nll, (logits, labels, m, z)
 
 
+@jax.named_scope("xent")
 def _fused_xent_bwd(res, g):
     logits, labels, m, z = res
     x = logits.astype(jnp.float32)

@@ -3,8 +3,12 @@
   events.py   TraceRecorder — structured spans (local_step / ef_encode /
               collective / ckpt / eval) on one perf_counter clock, with
               modeled device/wire costs attached from roofline + comm;
+              HostSpans — the train loop's ``train.*`` spans on the
+              profiler's clock, which give the recorder its boundaries;
   chrome.py   lossless Chrome trace_event export (Perfetto: workers as
               rows, sync rounds as flow arrows);
+  scopes.py   the step programs' named scopes (``jax.named_scope``) and
+              ``op_scopes``, which maps each compiled instruction to one;
   replay.py   trace-driven what-if engine — re-simulate the recorded
               critical path under substituted fabric / workers / H /
               threshold / codec / collective-count knobs, and the CI gate

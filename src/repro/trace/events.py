@@ -47,6 +47,11 @@ for the timeline view, their durations are the model.
 The JSON schema (``Trace.to_dict``) is versioned and lossless: spans
 round-trip through ``save``/``load`` and through the Chrome exporter
 bit-identically (``tests/test_trace.py``).
+
+:class:`HostSpans` gives the train loop its span boundaries: each
+``with`` block is a ``jax.profiler`` annotation named ``train.<name>``,
+on the device trace's clock in any profiler trace of the run, and is
+timed on the recorder's clock for the spans above.
 """
 from __future__ import annotations
 
@@ -55,7 +60,7 @@ import json
 import math
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 #: the span vocabulary — new kinds require a schema version bump.
 SPAN_KINDS = ("local_step", "ef_encode", "collective", "ckpt", "eval")
@@ -226,3 +231,52 @@ class TraceRecorder:
 
     def save(self, path: str) -> None:
         self.freeze().save(path)
+
+
+class HostSpans:
+    """The train loop's host spans, on the profiler's clock and the
+    recorder's.
+
+    ``spans("dispatch")`` is a ``jax.profiler.TraceAnnotation`` named
+    ``train.dispatch``, and ``spans.step(n, kind=...)`` a
+    ``StepTraceAnnotation`` named ``train.step``: in a ``jax.profiler``
+    trace of the run they lie on the device trace's clock, and with no
+    profiler running each costs one inactive TraceMe check. Every span's
+    start and length on the recorder's clock (``time.perf_counter``
+    without one) are kept in :attr:`last`, and a span given ``record``
+    also adds that kind of :class:`Span` to the recorder, so the loop's
+    spans and metrics take the same boundaries.
+    """
+
+    PREFIX = "train."
+
+    def __init__(self, recorder: Optional[TraceRecorder] = None) -> None:
+        from jax import profiler
+        self._annotation = profiler.TraceAnnotation
+        self._step = profiler.StepTraceAnnotation
+        self.recorder = recorder
+        self.clock = (recorder.now if recorder is not None
+                      else time.perf_counter)
+        self.last: Dict[str, Tuple[float, float]] = {}
+
+    def step(self, step: int, **args: Any):
+        """The span of one whole step; ``args`` land on the trace event."""
+        return self._step(self.PREFIX + "step", step_num=step, **args)
+
+    @contextmanager
+    def __call__(self, name: str, *, record: Optional[str] = None,
+                 step: int = -1, **args: Any) -> Iterator[None]:
+        t0 = self.clock()
+        try:
+            with self._annotation(self.PREFIX + name):
+                yield
+        finally:
+            dur = self.clock() - t0
+            self.last[name] = (t0, dur)
+            if record is not None and self.recorder is not None:
+                self.recorder.add(record, step=step, t0=t0, dur=dur, **args)
+
+    def since(self, name: str) -> Tuple[float, float]:
+        """(start of the latest ``name`` span, the time from it to now)."""
+        t0 = self.last[name][0]
+        return t0, self.clock() - t0
