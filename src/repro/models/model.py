@@ -29,10 +29,16 @@ def _compute_dtype(cfg):
 # --------------------------------------------------------------------------- #
 @jax.named_scope("xent")
 def softmax_xent(logits, labels, mask=None):
-    """Mean token cross-entropy in fp32. logits: (B,S,V), labels: (B,S)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    """Mean token cross-entropy in fp32. logits: (B,S,V), labels: (B,S).
+
+    The gold logit is a masked sum over the vocabulary, which XLA fuses
+    into the logsumexp's reductions. A gather, from the fp32 logits or
+    from the logits' own dtype, makes XLA write out a full (B,S,V) copy of
+    the logits just to read one entry per token back."""
+    x = logits.astype(jnp.float32)
+    logz = jax.nn.logsumexp(x, axis=-1)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    gold = jnp.sum(jnp.where(iota == labels[..., None], x, 0.0), axis=-1)
     nll = logz - gold
     if mask is None:
         return jnp.mean(nll)

@@ -3,12 +3,15 @@
 JAX's TPU compiler compiles for a topology it is only told about. These
 tests compile the training path's six Pallas entry points in Mosaic at
 biglstm's real leaf sizes, and the published model's per-leaf step, whose
-memory must fit one v5e chip. The topology is described inside a fixture
-(never at import: only one process may load the TPU library at a time),
-and the persistent compilation cache is off around these compiles.
+memory must fit one v5e chip, and whose cross-entropy must not write an
+fp32 copy of the (tokens x vocabulary) logits. The topology is described
+inside a fixture (never at import: only one process may load the TPU
+library at a time), and the persistent compilation cache is off around
+these compiles.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +33,33 @@ from repro.models import build_model
 BIGLSTM = get_arch("biglstm")
 SHARE = dataclasses.replace(BIGLSTM, vocab_size=99_184)   # 1/8 of the rows
 V5E_HBM_BYTES = 15.75e9     # what one v5e chip offers a program
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s=(]+)\s.*\{\s*$")
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?\S+ = (.*?) [\w-]+\(")
+_FUSED = re.compile(r"\bfusion\(.*calls=%?([\w.-]+)")
+_F32 = re.compile(r"f32\[([\d,]*)\]")
+
+
+def f32_token_vocab_arrays(hlo_text, batch, seq, vocab):
+    """The instructions of a compiled program that write an fp32 array
+    holding both token dimensions (or their product) and the vocabulary:
+    a full-vocabulary fp32 copy of the logits. Instructions inside a
+    fusion's body write nothing to memory and are not counted."""
+    fused = set(_FUSED.findall(hlo_text))
+    found, computation = [], None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head is not None and " = " not in line:
+            computation = head.group(1)
+            continue
+        result = _RESULT.match(line)
+        if computation in fused or result is None:
+            continue
+        for dims in _F32.findall(result.group(1)):
+            d = [int(n) for n in dims.split(",") if n]
+            if vocab in d and ((batch in d and seq in d) or batch * seq in d):
+                found.append(line.strip())
+    return found
 
 
 @pytest.fixture(scope="module")
@@ -122,7 +152,9 @@ def test_kernel_compiles_for_mosaic(one_chip, name):
 
 def test_published_biglstm_step_fits_one_chip(topo):
     """biglstm at its published widths (vocabulary 793,471), per-leaf,
-    fp32 wire, batch 8 x seq 20: the local step fits one chip."""
+    fp32 wire, batch 8 x seq 20: the local step fits one chip, and writes
+    no fp32 (tokens x vocabulary) array; the fp32 B² state of ``head_w``,
+    [512, 793471], is no such array."""
     shape = ShapeConfig(name="t", seq_len=20, global_batch=8, kind="train")
     opt = OptimizerConfig.from_sync(SyncConfig(), name="local_adaalter",
                                     lr=0.5, H=2, warmup_steps=100)
@@ -139,3 +171,20 @@ def test_published_biglstm_step_fits_one_chip(topo):
     # params (bf16) + b2_sync + b2_local (fp32) are the arguments
     assert ma.argument_size_in_bytes > 8e9, ma
     assert total < V5E_HBM_BYTES, ma
+    text = compiled.as_text()
+    assert re.search(rf"f32\[(1,)?512,{BIGLSTM.vocab_size}\]", text)
+    assert not f32_token_vocab_arrays(text, 8, 20, BIGLSTM.vocab_size)
+
+
+def test_share_loss_grad_writes_no_fp32_logits(one_chip):
+    """The LSTM loss's value_and_grad at the 1/8 vocabulary share (V =
+    99,184), batch 8 x seq 20: no fp32 (tokens x vocabulary) array."""
+    model = build_model(SHARE)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((8, 20), jnp.int32, sharding=one_chip)
+    batch = {"tokens": tokens, "labels": tokens}
+    grad = jax.value_and_grad(lambda p, b: model.loss_fn(p, b)[0])
+    text = jax.jit(grad).lower(params, batch).compile().as_text()
+    assert not f32_token_vocab_arrays(text, 8, 20, SHARE.vocab_size)
