@@ -29,6 +29,8 @@ ROOT = Path(__file__).resolve().parents[1]
 
 # names train_loop resolves from repro.launch.train's namespace
 WRAPPED = ("build_train_programs", "make_train_batch", "train_loop")
+# keys of a configuration file that describe it and size nothing
+DOC_KEYS = ("published", "deployment", "assumed", "reduced")
 
 
 class StopWindow(Exception):
@@ -49,6 +51,7 @@ class Cell:
     limits: Optional[dict]
     end_to_end: List[dict]
     per_layer: List[dict]
+    model_file: Optional[str] = None    # the configuration's file, for errors
 
     @classmethod
     def load(cls, name: str, root: Path = ROOT) -> "Cell":
@@ -59,7 +62,8 @@ class Cell:
                            f"known: {sorted(cells)}")
         w = cells[name]
         configs = {c["name"]: c for c in bench["configs"]}
-        model = _read_json(root / configs[w["config"]]["file"])
+        model_file = configs[w["config"]]["file"]
+        model = _read_json(root / model_file)
         traffic = _read_json(root / "chipbench" / "traffic"
                              / f"{w['traffic']}.json")
         lim_path = root / "chipbench" / "limits" / f"{name}.json"
@@ -71,7 +75,8 @@ class Cell:
         return cls(name=name, chips=int(w["chips"]), model=model,
                    traffic=traffic, limits=limits,
                    end_to_end=[m for m in bench["end_to_end"] if applies(m)],
-                   per_layer=[m for m in bench["per_layer"] if applies(m)])
+                   per_layer=[m for m in bench["per_layer"] if applies(m)],
+                   model_file=model_file)
 
     @property
     def workers(self) -> int:
@@ -82,10 +87,19 @@ class Cell:
         return int(self.traffic["H"])
 
     def program_configs(self):
-        """(ModelConfig, ShapeConfig, OptimizerConfig) of the system."""
+        """(ModelConfig, ShapeConfig, OptimizerConfig) of the system.
+
+        A key of the configuration that is neither a ``ModelConfig`` field
+        nor documentary (``DOC_KEYS``) is refused: dropped, it would leave
+        the program at another size than the reference reads."""
         from repro.configs.base import (ModelConfig, OptimizerConfig,
                                         ShapeConfig, SyncConfig)
         fields = {f.name for f in dataclasses.fields(ModelConfig)}
+        unknown = sorted(set(self.model) - fields - set(DOC_KEYS))
+        if unknown:
+            raise ValueError(
+                f"configuration {self.model_file or self.model.get('name')}"
+                f" has keys that ModelConfig lacks: {unknown}")
         cfg = ModelConfig(**{k: v for k, v in self.model.items()
                              if k in fields})
         t = self.traffic

@@ -29,6 +29,24 @@ import jax.numpy as jnp
 # largest logits block the head materialises at once (elements)
 HEAD_BLOCK_ELEMS = 1 << 26
 
+# The limits of the CPU size that ``small`` gives, set on the CPU from its
+# readings over seeds 1-3: sound runs read at most loss 4.5e-5, grad 0.017,
+# update 0.04 and sync 0.2 (the four-worker mean at bf16 moves a few
+# elements of these small leaves by one ulp or not); the control reads loss
+# 1.5e-4 or more, half the batch grad 0.45 or more, the frozen step 1, the
+# missing exchange sync 2.3 or more. The chip's limits (``limits/``) are
+# set the same way at the cells' own sizes.
+SMALL_LIMITS = {"loss": 1e-4, "grad": 0.1, "update": 0.2, "sync": 0.5}
+
+
+def small(cfg: dict) -> dict:
+    """The keys that shrink a configuration to the CPU size: hidden 256,
+    projection 64, and a vocabulary of 8000, or of 1000 where the
+    configuration's own ``reduced`` cuts the vocabulary to a slice."""
+    sliced = "vocab_size" in cfg.get("reduced", {})
+    return {"d_model": 256, "lstm_proj": 64,
+            "vocab_size": 1000 if sliced else 8000}
+
 
 def init(cfg: dict, key):
     """Weights in their stored dtype, drawn as the system draws them."""

@@ -5,16 +5,12 @@ chip skipped, at the cell's own job (layout, wire, H, workers) but reduced
 widths, batch and sequence. A sound run comes out correct; the control
 (calibration's reference in float8, put in the program's place) and each
 planted fault of ``check.FAULTS`` that the cell can have come out not
-correct. The
+correct. The one-chip cells are those of ``BENCHMARK.json``; the
 four-worker cell runs in a subprocess with four virtual CPU devices.
 
-The limits here are this reduced size's own (``SMALL_LIMITS``), set on the
-CPU from its readings over seeds 1-3: sound runs read at most loss 4.5e-5,
-grad 0.017, update 0.04 and sync 0.2 (the four-worker mean at bf16 moves a
-few elements of these small leaves by one ulp or not); the control reads
-loss 1.5e-4 or more, half the batch grad 0.45 or more, the frozen step 1,
-the missing exchange sync 2.3 or more. The chip's limits (``limits/``) are
-set the same way at the cells' own sizes.
+Each configuration is shrunk by its family's ``small`` and each cell
+judged by its family's ``SMALL_LIMITS`` (``chipbench/models/``), which say
+how those limits were set.
 """
 import json
 import os
@@ -26,34 +22,45 @@ from pathlib import Path
 
 import pytest
 
+from chipbench.reference import family_module
+
 ROOT = Path(__file__).resolve().parents[2]
-SMALL = {"d_model": 256, "lstm_proj": 64}
-SMALL_VOCAB = {"biglstm": 8000, "biglstm-vocab8": 1000}
-SMALL_LIMITS = {"loss": 1e-4, "grad": 0.1, "update": 0.2, "sync": 0.5}
-ONE_CHIP = ["biglstm.h4-fp32", "biglstm-vocab8.h4-int8-flat"]
+ONE_CHIP = [w["name"] for w in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["workloads"] if w["chips"] == 1]
 FOUR_CHIP = "biglstm.4x1-h4-fp32"
 
 
-def make_root(dst: Path) -> Path:
-    """A copy of the benchmark with every configuration and job shrunk."""
+def make_root(dst: Path, src: Path = ROOT) -> Path:
+    """A copy of the benchmark at ``src`` with every configuration and job
+    shrunk: each configuration by its family's ``small``, each cell's
+    limits its family's ``SMALL_LIMITS``."""
     (dst / "chipbench" / "limits").mkdir(parents=True)
-    for sub in ("traffic", "configs"):
-        shutil.copytree(ROOT / "chipbench" / sub, dst / "chipbench" / sub)
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    shutil.copytree(src / "chipbench" / "traffic",
+                    dst / "chipbench" / "traffic")
+    bench = json.loads((src / "BENCHMARK.json").read_text())
     # the four-worker job (traffic/4x1-h4-fp32.json) is tested here too,
     # though its cell waits for the chip runs that would prove it
     if FOUR_CHIP not in {w["name"] for w in bench["workloads"]}:
         bench["workloads"].append({"name": FOUR_CHIP, "config": "biglstm",
                                    "traffic": "4x1-h4-fp32", "chips": 4,
                                    "why": "4 workers x 1 chip"})
+    family = {}
+    for c in bench["configs"]:
+        m = json.loads((src / c["file"]).read_text())
+        fam = family_module(m["family"])
+        missing = [n for n in ("small", "SMALL_LIMITS") if not hasattr(fam, n)]
+        if missing:
+            raise AttributeError(f"family module {fam.__name__} does not "
+                                 f"define {', '.join(missing)} (see "
+                                 "chipbench/models/__init__.py)")
+        m.update(fam.small(m))
+        family[c["name"]] = fam
+        p = dst / c["file"]
+        p.parent.mkdir(parents=True, exist_ok=True)
+        p.write_text(json.dumps(m))
     for w in bench["workloads"]:
         (dst / "chipbench" / "limits" / f"{w['name']}.json").write_text(
-            json.dumps(SMALL_LIMITS))
-    for c in bench["configs"]:
-        p = dst / c["file"]
-        m = json.loads(p.read_text())
-        m.update(SMALL, vocab_size=SMALL_VOCAB[c["name"]])
-        p.write_text(json.dumps(m))
+            json.dumps(family[w["config"]].SMALL_LIMITS))
     for p in (dst / "chipbench" / "traffic").glob("*.json"):
         t = json.loads(p.read_text())
         t.update(batch_per_worker=8, seq_len=16 if t["seq_len"] > 20 else 5)
